@@ -26,11 +26,7 @@ the replay is bit-consistent with `make_replay_batched`
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
-import textwrap
 import time
 
 import numpy as np
@@ -58,45 +54,37 @@ SHARDED_SHARDS = 2               # mesh width of the sharded churn cell
 # Sharded churn cell (DESIGN.md §15): the same fixed-churn rolling trace
 # replayed through an AcaiCache on a (1, SHARDS) mesh — mutation routed to
 # owner shards, serving through the sharded exact masked scan, epoch
-# compaction keeping the slab mesh-aligned.  Runs in a subprocess because
-# the virtual device count must be fixed before jax initialises (and the
-# parent's single-device cells must not inherit a split threadpool).  The
-# config mirrors the in-process acai-exact cell (same h/k/eta defaults),
-# so its NAG is directly comparable to the shards=1 rows.
-_SHARDED_CHILD = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count={shards} "
-        + os.environ.get("XLA_FLAGS", ""))
-    import json
-    import time
-    import jax, jax.numpy as jnp
-    import numpy as np
-    from repro.core import churn, oma, policy, trace
-    from repro.core.costs import calibrate_fetch_cost
+# compaction keeping the slab mesh-aligned.  It runs in this process on
+# the first SHARDS devices (virtual ones on the CPU, set up by
+# `benchmarks.run`; on a chip a mesh wider than the chips is refused).
+# The config mirrors the in-process acai-exact cell (same h/k/eta
+# defaults), so its NAG is directly comparable to the shards=1 rows.
+def _run_sharded_cell(n, d, t, rate, h, k, *, shards, compact_every):
+    import jax.numpy as jnp
 
-    n, d, t, rate, warm = {n}, {d}, {t}, {rate}, {warm}
-    shards, h, k, batch, compact_every = (
-        {shards}, {h}, {k}, {batch}, {compact_every})
+    from repro.core import oma
+    from repro.launch.mesh import make_mesh, shard_devices
+
+    mesh = make_mesh((1, shards), ("data", "model"),
+                     devices=shard_devices(shards))
     catalog, reqs, _ = trace.build_trace(
-        "rolling_catalog", n=n, d=d, t=t, churn_rate=rate, warm=warm,
+        "rolling_catalog", n=n, d=d, t=t, churn_rate=rate, warm=WARM,
         seed=17)
     events = trace.rolling_catalog_events(n=n, t=t, churn_rate=rate,
-                                          warm=warm)
-    n0 = churn.warm_size(n, warm)
+                                          warm=WARM)
+    n0 = churn.warm_size(n, WARM)
     c_f = float(calibrate_fetch_cost(jnp.asarray(catalog[:n0]),
                                      kth=min(50, n0 - 1), sample=256))
     cfg = policy.AcaiConfig(h=h, k=k, c_f=c_f,
                             oma=oma.OMAConfig(eta=0.05 / c_f))
-    mesh = jax.make_mesh((1, shards), ("data", "model"))
     pol = policy.AcaiCache(jnp.asarray(catalog[:n0]), cfg, seed=0,
                            mesh=mesh)
     t0 = time.time()
-    res = churn.replay_with_churn(pol, catalog, reqs, events, batch=batch,
+    res = churn.replay_with_churn(pol, catalog, reqs, events, batch=BATCH,
                                   compact_every=compact_every)
     wall = time.time() - t0
     tt = res["requests"]
-    print(json.dumps({{
+    return {
         "label": "acai-exact", "index": "exact", "shards": shards,
         "refresh_every": 0, "compact_every": compact_every,
         "events": res["events_applied"],
@@ -111,30 +99,8 @@ _SHARDED_CHILD = textwrap.dedent("""
         "compact_ms": round(res["compact_s"] * 1e3, 1),
         "compactions": res["compactions"],
         "us_per_request": round(wall / tt * 1e6, 2),
-        "requests": tt, "ndev": jax.device_count(),
-    }}))
-""")
-
-
-def _run_sharded_cell(n, d, t, rate, h, k, *, shards, compact_every):
-    root = pathlib.Path(__file__).resolve().parent.parent
-    child = _SHARDED_CHILD.format(n=n, d=d, t=t, rate=rate, warm=WARM,
-                                  shards=shards, h=h, k=k, batch=BATCH,
-                                  compact_every=compact_every)
-    out = subprocess.run(
-        [sys.executable, "-c", child], capture_output=True, text=True,
-        timeout=3600,
-        env={**os.environ,
-             "PYTHONPATH": str(root / "src") + (
-                 os.pathsep + os.environ["PYTHONPATH"]
-                 if os.environ.get("PYTHONPATH") else "")})
-    if out.returncode != 0:
-        raise RuntimeError(f"sharded churn child (shards={shards}) "
-                           f"failed:\n{out.stderr[-3000:]}")
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    if row.pop("ndev") != shards:
-        raise RuntimeError("sharded churn child ran without its mesh")
-    return row
+        "requests": tt,
+    }
 
 
 def _policies(c_f: float, h: int, k: int, full: bool = False):
@@ -364,7 +330,7 @@ def main(full: bool = False, kind: str = None) -> None:
             f"compactions={row['compactions']}")
 
     # sharded churn cell: the same fixed-churn + compaction workload on a
-    # (1, 2) mesh (subprocess, see _SHARDED_CHILD).  Skipped at --full:
+    # (1, 2) mesh (see _run_sharded_cell).  Skipped at --full:
     # host-emulated shards at 1M x 128 measure threadpool contention, not
     # the mesh mutation path (which the 2k-scale cell already exercises).
     if not full:

@@ -10,12 +10,13 @@ the smallest catalog where a 4-shard mesh beats 1 shard — the fused
 per-step collective budget (DESIGN.md §15) is a fixed cost, the per-shard
 scan is O(n / P), so the crossover moves with n.
 
-Each shard count runs in its own subprocess with exactly that many
-placeholder devices: the device count must be fixed before jax initialises
-(same discipline as launch/dryrun.py), and forcing 8 devices for the
-1-shard row would split the host threadpool 8 ways and poison the
-comparison against the single-device pipeline numbers (measured ~3x tax).
-The 1-shard child additionally asserts its replay is BITWISE identical to
+Every shard count runs in this process on the first P of its devices
+(`repro.launch.mesh.shard_devices`): one process owns the chip, so no
+child could reach it.  On the CPU, `benchmarks.run` gives the process 8
+virtual devices before jax starts; on a chip a shard count above the
+device count is refused.
+
+The 1-shard row additionally asserts its replay is BITWISE identical to
 `make_replay_batched` + exact candidates (the `bit_consistent` column) —
 the bench refuses to publish numbers for a path that drifted from the
 reference policy.  Every row also carries the statically counted
@@ -31,34 +32,27 @@ of this file on real hardware.
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
-import textwrap
+import time
+
+import numpy as np
+import jax
+import jax.numpy as jnp
 
 from benchmarks import common
+from repro.core import oma, policy, trace
+from repro.core.costs import calibrate_fetch_cost
+from repro.core.distributed import collectives_per_step, make_step_sharded
+from repro.launch.mesh import make_mesh, shard_devices
 
 BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / "BENCH_distributed.json"
 
-_CHILD = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count={shards} "
-        + os.environ.get("XLA_FLAGS", ""))
-    import json
-    import time
-    import numpy as np
-    import jax, jax.numpy as jnp
-    from repro.core import oma, policy, trace
-    from repro.core.costs import calibrate_fetch_cost
-    from repro.core.distributed import (collectives_per_step,
-                                        make_step_sharded)
 
-    t, d, kind, shards, n_sweep = {t}, {d}, {kind!r}, {shards}, {n_sweep}
+def _run_shards(t: int, d: int, kind: str, shards: int, n_sweep) -> list:
     gen = trace.sift_like if kind == "sift" else trace.amazon_like
     rows = []
-    mesh = jax.make_mesh((1, shards), ("data", "model"))
+    mesh = make_mesh((1, shards), ("data", "model"),
+                     devices=shard_devices(shards))
     for n in n_sweep:
         catalog, reqs, _ = gen(n=n, d=d, t=t, seed=0)
         cat, reqs_j = jnp.array(catalog), jnp.array(reqs)
@@ -96,17 +90,15 @@ _CHILD = textwrap.dedent("""
                     n, b)
                 bit = True
             nag = float(np.sum(np.asarray(m.gain_int))) / (cfg.k * c_f * tt)
-            rows.append({{
+            rows.append({
                 "shards": shards, "batch": b, "n": n,
                 "candidates": "exact-sharded",
                 "requests_per_s": round(tt / dt, 1),
                 "us_per_request": round(dt / tt * 1e6, 2),
                 "nag": round(nag, 4), "requests": tt,
                 "collectives_per_step": coll, "bit_consistent": bit,
-            }})
-    print(json.dumps({{"rows": rows, "ndev": jax.device_count(),
-                       "backend": jax.default_backend()}}))
-""")
+            })
+    return rows
 
 
 def _break_even(rows: list) -> tuple[dict, str]:
@@ -135,24 +127,11 @@ def _break_even(rows: list) -> tuple[dict, str]:
 def main(full: bool = False, kind: str = "sift") -> None:
     t, d = (8192, 32) if full else (2048, 16)
     n_sweep = (5000, 20000, 80000) if full else (512, 2048, 8192)
-    rows, ndev, backend = [], {}, None
+    rows, ndev = [], {}
     for shards in (1, 4, 8):
-        child = _CHILD.format(t=t, d=d, kind=kind, shards=shards,
-                              n_sweep=n_sweep)
-        out = subprocess.run(
-            [sys.executable, "-c", child], capture_output=True, text=True,
-            timeout=3600,
-            env={**os.environ,
-                 "PYTHONPATH": str(BENCH_JSON.parent / "src") + (
-                     os.pathsep + os.environ["PYTHONPATH"]
-                     if os.environ.get("PYTHONPATH") else "")})
-        if out.returncode != 0:
-            raise RuntimeError(f"distributed bench child (shards={shards}) "
-                               f"failed:\n{out.stderr[-3000:]}")
-        res = json.loads(out.stdout.strip().splitlines()[-1])
-        rows += res["rows"]
-        ndev[str(shards)] = res["ndev"]
-        backend = res["backend"]
+        rows += _run_shards(t, d, kind, shards, n_sweep)
+        ndev[str(shards)] = shards
+    backend = jax.default_backend()
     for row in rows:
         common.emit(
             f"distributed/{kind}/n{row['n']}/shards{row['shards']}"

@@ -34,6 +34,12 @@ def main() -> None:
                     help="print registered suites/grids/policies/traces")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import cpu_host_devices
+
+    # the sharded suites build meshes of up to 8 shards in this process
+    cpu_host_devices(8)
+    enable_compile_cache()
     from benchmarks import (answer_cache_bench, backends_bench, churn_bench,
                             distributed_bench,
                             experiments, fig1_gain_vs_requests,
@@ -61,8 +67,8 @@ def main() -> None:
         # tracked per PR
         "pipeline": (serve_bench.pipeline_main, ["sift"],
                      "BENCH_pipeline.json"),
-        # sharded multi-device replay (8 placeholder devices, subprocess):
-        # shards∈{1,4,8} × B∈{8,64}
+        # sharded multi-device replay, in process (8 virtual devices on
+        # the CPU): shards∈{1,4,8} × B∈{8,64}
         "distributed": (distributed_bench.main, ["sift"],
                         "BENCH_distributed.json"),
         # unified-index-API sweep: every registered backend × B∈{8,64},

@@ -288,6 +288,7 @@ from repro.core import oma, policy, trace
 from repro.core.distributed import (make_replay_sharded,
                                     make_retrieval_step, reference_step)
 from repro.index import IndexSpec, build_index
+from repro.launch.mesh import make_mesh
 
 assert jax.device_count() == 2, jax.devices()
 N, d, B, C, k, h = 256, 16, 4, 16, 4, 24
@@ -295,7 +296,7 @@ rng = np.random.default_rng(0)
 catalog = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
 y0 = jnp.full((N,), h / N, jnp.float32)
 reqs = jnp.asarray(rng.normal(size=(B, d)), jnp.float32)
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 
 # retrieval step (full-matrix + fused chunked scan) vs reference
 for chunk in (0, 32):
@@ -332,6 +333,7 @@ python - <<'EOF'
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.core import churn, oma, policy, trace
+from repro.launch.mesh import make_mesh
 
 assert jax.device_count() == 2, jax.devices()
 params = dict(trace.TINY_TRACE_KWARGS["rolling_catalog"])
@@ -343,7 +345,7 @@ cfg = policy.AcaiConfig(h=16, k=4, c_f=1.0, c_remote=16, c_local=8,
 
 # mutation + epoch compaction on a real 2-shard mesh (DESIGN.md §15)
 pol2 = policy.AcaiCache(jnp.asarray(catalog[:n0]), cfg, seed=0,
-                        mesh=jax.make_mesh((1, 2), ("data", "model")))
+                        mesh=make_mesh((1, 2), ("data", "model")))
 res2 = churn.replay_with_churn(pol2, catalog, reqs, events, batch=8,
                                compact_every=24)
 assert res2["events_applied"] == len(events) > 0
@@ -353,7 +355,7 @@ assert pol2.catalog.shape[0] % 2 == 0          # slab stays mesh-aligned
 
 # 1-device-mesh parity: the sharded mutable path IS the mutable path
 pol1 = policy.AcaiCache(jnp.asarray(catalog[:n0]), cfg, seed=0,
-                        mesh=jax.make_mesh((1, 1), ("data", "model")))
+                        mesh=make_mesh((1, 1), ("data", "model")))
 res1 = churn.replay_with_churn(pol1, catalog, reqs, events, batch=8,
                                compact_every=24)
 plain = policy.AcaiCache(jnp.asarray(catalog[:n0]), cfg, seed=0)

@@ -44,7 +44,9 @@ def pairwise_dissimilarity(
     A Pallas-kernelised version lives in repro.kernels.ops.pairwise_l2.
     """
     queries = jnp.atleast_2d(queries)
-    dots = queries @ points.T
+    # f32 products: on the TPU the default is one bf16 MXU pass, whose
+    # error reorders near neighbours
+    dots = jnp.matmul(queries, points.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return -dots
     qn = jnp.sum(queries * queries, axis=-1, keepdims=True)

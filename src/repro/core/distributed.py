@@ -42,9 +42,6 @@ churn twin: catalog slab + liveness mask as runtime arguments, mutations
 routed to the owning shard by global-id arithmetic (`route_ids_by_owner`,
 `sharded_slab_append`), the projection run over the live mask — bitwise
 `make_mutable_step` + exact candidates on a 1-device mesh (DESIGN.md §15).
-
-All shard_map usage goes through `repro.compat` so the module lowers on
-every supported jax version.
 """
 
 from __future__ import annotations
@@ -55,9 +52,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.extend import core as jax_core
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import gain as gain_lib
 from repro.core import mirror as mirror_maps
 from repro.core import oma as oma_lib
@@ -315,9 +313,9 @@ def _count_collectives(jaxpr, counts: dict) -> None:
                 break
         for v in eqn.params.values():
             for item in (v if isinstance(v, (list, tuple)) else (v,)):
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, jax_core.ClosedJaxpr):
                     _count_collectives(item.jaxpr, counts)
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, jax_core.Jaxpr):
                     _count_collectives(item, counts)
 
 

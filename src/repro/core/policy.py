@@ -322,11 +322,10 @@ def make_replay(cfg: AcaiConfig, candidate_fn: Callable) -> Callable:
     """Whole-trace replay: (state, requests (T,d)) -> (state', StepMetrics (T,))."""
     step = make_step(cfg, candidate_fn)
 
-    @jax.jit
     def replay(state: CacheState, requests: jax.Array):
         return jax.lax.scan(step, state, requests)
 
-    return replay
+    return jit_closed_over_as_args(replay)
 
 
 def finish_step_batched(cfg_up: AcaiConfig, state: CacheState, key, k_round,
@@ -474,7 +473,6 @@ def make_replay_from_step(step: Callable, batch: int) -> Callable:
     `make_replay_batched` and `repro.core.distributed.make_replay_sharded`
     — one replay contract, two step implementations."""
 
-    @jax.jit
     def replay(state: CacheState, requests: jax.Array):
         t, dim = requests.shape
         assert t % batch == 0, (
@@ -487,7 +485,7 @@ def make_replay_from_step(step: Callable, batch: int) -> Callable:
             lambda a: a.reshape(t, *a.shape[2:]), m
         )
 
-    return replay
+    return jit_closed_over_as_args(replay)
 
 
 def make_replay_batched(
@@ -503,6 +501,64 @@ def make_replay_batched(
     """
     return make_replay_from_step(
         make_step_batched(cfg, candidate_fn_batched, batch, eta_scale), batch)
+
+
+# Closed-over arrays at least this large reach a compiled step as runtime
+# arguments (see `jit_closed_over_as_args`); smaller ones stay constants.
+ARG_BYTES = 1 << 20
+
+
+def jit_closed_over_as_args(fn: Callable) -> Callable:
+    """`jax.jit(fn)`, except that the large arrays `fn` closes over — the
+    catalog, index tables, a sharded catalog's shards — reach the compiled
+    program as runtime arguments instead of being baked into it as
+    constants.  A 1M x 128 catalog is 512 MiB: as a constant it would be
+    serialised into the program, constant-folded by the compiler and held
+    a second time in device memory by the executable, and a sharded one
+    would lose its placement.  Arrays under ARG_BYTES stay constants, so
+    a small catalog compiles exactly as under `jax.jit` (the bitwise pins
+    between this module's paths rely on one treatment per size).
+
+    `fn` is traced once per argument signature (tree structure, shapes,
+    dtypes); `.lower(*args)` gives the lowered program and `._cache_size()`
+    the number of compiled programs, as for a jit."""
+    cache: dict = {}
+
+    def runner(args):
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        key = (tree, tuple((np.shape(a), jnp.result_type(a)) for a in leaves))
+        hit = cache.get(key)
+        if hit is None:
+            closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+            out_tree = jax.tree_util.tree_structure(out_shape)
+            consts = list(closed.consts)
+            big = [i for i, c in enumerate(consts)
+                   if np.size(c) * jnp.result_type(c).itemsize >= ARG_BYTES]
+
+            @jax.jit
+            def run(big_vals, *flat):
+                cs = list(consts)
+                for i, v in zip(big, big_vals):
+                    cs[i] = v
+                out = jax.core.eval_jaxpr(closed.jaxpr, cs, *flat)
+                return jax.tree_util.tree_unflatten(out_tree, out)
+
+            hit = cache[key] = (run, [consts[i] for i in big])
+        run, big_vals = hit
+        return run, big_vals, leaves
+
+    def call(*args):
+        run, big_vals, leaves = runner(args)
+        return run(big_vals, *leaves)
+
+    def lower(*args):
+        run, big_vals, leaves = runner(args)
+        return run.lower(big_vals, *leaves)
+
+    call.lower = lower
+    call._cache_size = lambda: sum(run._cache_size() for run, _ in
+                                   cache.values())
+    return call
 
 
 class AcaiCache:
@@ -656,7 +712,7 @@ class AcaiCache:
             self._fn_batched = candidate_fn_batched
             if candidate_fn is None:
                 candidate_fn = per_request_view(candidate_fn_batched)
-            self._step = jax.jit(make_step(cfg, candidate_fn))
+            self._step = jit_closed_over_as_args(make_step(cfg, candidate_fn))
         # answer-cache tier (DESIGN.md §13): wrap the spec-built index in
         # a CachedIndex and serve through the two-stage mutable path from
         # step 0 — the static jitted step queries the index inside its
@@ -684,7 +740,7 @@ class AcaiCache:
             self.index = CachedIndex(self.index, ac_spec)
             self.answer_cache = self.index
             self._enter_mutable()
-        self.state = init_state(catalog.shape[0], cfg, seed=seed)
+        self.state = self._placed(init_state(catalog.shape[0], cfg, seed=seed))
         # resilient serving mode (DESIGN.md §11): None until a
         # RemoteBackend is attached; then serve_update(_batch) dispatch
         # through the retry/degrade ladder in repro.serve.resilience.
@@ -711,6 +767,21 @@ class AcaiCache:
                 "single-device cache")
         self._res = AcaiResilience(self, remote, resilience)
         return self._res
+
+    def _placed(self, state: CacheState) -> CacheState:
+        """`state` placed where the jitted step returns it — y and x split
+        over the mesh's model axis with the counters replicated, or on the
+        catalog's device — so the second batch runs the first batch's
+        compiled program instead of compiling one for a new placement."""
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            axis = self._sharded_kwargs.get("model_axis", "model")
+            rows = NamedSharding(self.mesh, PartitionSpec(axis))
+            rep = NamedSharding(self.mesh, PartitionSpec())
+            return jax.device_put(state, CacheState(rows, rows, rep, rep))
+        sharding = getattr(self.catalog, "sharding", None)
+        return state if sharding is None else jax.device_put(state, sharding)
 
     def _sharded_step(self, batch: int) -> Callable:
         from repro.core.distributed import make_step_sharded
@@ -746,7 +817,7 @@ class AcaiCache:
                 state, m = b1(state, rr[None, :])
                 return state, jax.tree_util.tree_map(lambda a: a[0], m)
 
-            self._step = jax.jit(_step1)
+            self._step = jit_closed_over_as_args(_step1)
         self.state, metrics = self._step(self.state, r)
         return metrics
 
@@ -803,15 +874,25 @@ class AcaiCache:
                     answer_invalidations=jnp.zeros(
                         (b,), jnp.int32).at[0].set(int(inval)))
             return metrics
+        self.state, metrics = self._batch_step(b)(self.state, rs)
+        return metrics
+
+    def _batch_step(self, b: int) -> Callable:
+        """The static jitted step for batch size `b`, built once."""
         step = self._bsteps.get(b)
         if step is None:
-            if self.mesh is not None:
-                step = jax.jit(self._sharded_step(b))
-            else:
-                step = jax.jit(make_step_batched(self.cfg, self._fn_batched, b))
+            fn = (self._sharded_step(b) if self.mesh is not None
+                  else make_step_batched(self.cfg, self._fn_batched, b))
+            step = jit_closed_over_as_args(fn)
             self._bsteps[b] = step
-        self.state, metrics = step(self.state, rs)
-        return metrics
+        return step
+
+    def lower_batch(self, rs: jax.Array):
+        """The lowered static step `serve_update_batch(rs)` runs, for
+        inspecting the compiled program — e.g. that the scan is the Pallas
+        kernel (`tpu_custom_call` in its text) and not an XLA fallback."""
+        rs = jnp.atleast_2d(rs)
+        return self._batch_step(rs.shape[0]).lower(self.state, rs)
 
     # -- online catalog mutation (DESIGN.md §10) ----------------------------
 

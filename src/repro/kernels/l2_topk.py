@@ -1,12 +1,20 @@
-"""Fused distance + per-block partial top-k Pallas kernel.
+"""Fused distance + running top-k Pallas kernel.
 
 GPU FAISS fuses the distance GEMM with a warp-level k-selection network.
-TPU has no warp shuffles; the idiomatic two-level equivalent (DESIGN.md §3)
-is: each (BQ, BN) tile emits its k smallest distances (iterative masked-min
-extraction — k is small), and the host-side wrapper merges the per-block
-partial results with one `lax.top_k` over (Q, nblocks * k).  This avoids
-materialising the full (Q, N) distance matrix in HBM: the memory written
-drops from Q*N to Q*N*k/BN floats (k/BN ≈ 1/16 compression at k=8).
+TPU has no warp shuffles; the idiomatic equivalent (DESIGN.md §3) is a
+running merge: the grid walks the catalog in (BQ, BN) tiles, and each tile
+folds its distances into the k best seen so far for its queries by
+iterative masked-min extraction (k is small).  The k best live in the
+output block itself, which stays resident in VMEM across the catalog axis
+of the grid, so neither the (Q, N) distance matrix nor any per-block
+partials ever reach HBM: the kernel reads the catalog once and writes
+Q·k floats.
+
+Layout (DESIGN.md §4): a tile is scored as (BQ, BN) and transposed to
+(BN, BQ), so queries sit on the 128 lanes and the selection reduces over
+sublanes; the running top-k is (k, BQ), lane-dense, and the output array
+is (k, Q) — its block's last dim is 128 and its second-to-last is the
+array's full k, as the TPU's tiling rule asks.
 """
 
 from __future__ import annotations
@@ -16,82 +24,107 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BQ = 128
 BN = 128
 _INF = float("inf")  # python literal: avoids captured-constant tracing in Pallas
+_HIGHEST = jax.lax.Precision.HIGHEST  # f32 distances, not one bf16 MXU pass
 
 
-def extract_block_topk(d: jax.Array, base, k: int):
-    """Per-tile k smallest of d (BQ, BN) by iterative masked-min extraction
-    (k is small; no warp shuffles on TPU).  Returns (dists (BQ, k),
-    positions (BQ, k)) with positions offset by `base` — the shared
-    second-level contract of l2_topk and ivf_scan: callers merge the
-    per-block partials with one lax.top_k.  Exhausted tiles yield +inf."""
+def merge_block_topk(acc_d, acc_i, d, base, axis: int):
+    """Fold one tile into a running top-k by iterative masked-min
+    extraction — the shared selection step of l2_topk and ivf_scan.
+
+    acc_d / acc_i: the k best so far (ascending along `axis`) and their
+    ids; d: the tile's distances, candidates along `axis`, whose ids are
+    `base` + their position.  Returns the k smallest of both, ascending.
+    Ties go to the running set first, then to the lower tile position, so
+    a scan in id order breaks ties toward the lower id, as one `lax.top_k`
+    over the whole row would.  Slots are written with an iota select (the
+    TPU lowers no scatter); an exhausted tile contributes +inf."""
+    k, n = acc_d.shape[axis], d.shape[axis]
+    apos = jax.lax.broadcasted_iota(jnp.int32, acc_d.shape, axis)
+    tpos = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
 
     def body(t, carry):
-        d_cur, outd, outi = carry
-        m = jnp.min(d_cur, axis=1)                        # (BQ,)
-        a = jnp.argmin(d_cur, axis=1).astype(jnp.int32)   # (BQ,)
-        outd = outd.at[:, t].set(m)
-        outi = outi.at[:, t].set(base + a)
-        cols = jax.lax.broadcasted_iota(jnp.int32, d_cur.shape, 1)
-        d_cur = jnp.where(cols == a[:, None], _INF, d_cur)
-        return d_cur, outd, outi
+        ad, td, od, oi = carry
+        ma = jnp.min(ad, axis=axis, keepdims=True)
+        mt = jnp.min(td, axis=axis, keepdims=True)
+        from_acc = ma <= mt
+        pa = jnp.min(jnp.where(ad == ma, apos, k), axis=axis, keepdims=True)
+        pt = jnp.min(jnp.where(td == mt, tpos, n), axis=axis, keepdims=True)
+        ia = jnp.sum(jnp.where(apos == pa, acc_i, 0), axis=axis,
+                     keepdims=True)
+        slot = apos == t
+        od = jnp.where(slot, jnp.where(from_acc, ma, mt), od)
+        oi = jnp.where(slot, jnp.where(from_acc, ia, base + pt), oi)
+        ad = jnp.where(from_acc & (apos == pa), _INF, ad)
+        td = jnp.where(~from_acc & (tpos == pt), _INF, td)
+        return ad, td, od, oi
 
-    outd = jnp.full((d.shape[0], k), _INF, jnp.float32)
-    outi = jnp.zeros((d.shape[0], k), jnp.int32)
-    _, outd, outi = jax.lax.fori_loop(0, k, body, (d, outd, outi))
-    return outd, outi
+    _, _, od, oi = jax.lax.fori_loop(0, k, body, (acc_d, d, acc_d, acc_i))
+    return od, oi
 
 
-def _l2_topk_kernel(k: int, n_valid: int, masked: bool, *refs):
+def _l2_topk_kernel(n: int, masked: bool, *refs):
     if masked:
         q_ref, x_ref, m_ref, od_ref, oi_ref = refs
     else:
         q_ref, x_ref, od_ref, oi_ref = refs
         m_ref = None
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        od_ref[...] = jnp.full(od_ref.shape, _INF, jnp.float32)
+        oi_ref[...] = jnp.zeros(oi_ref.shape, jnp.int32)
+
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     dots = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
     )
     qn = jnp.sum(q * q, axis=1, keepdims=True)
     xn = jnp.sum(x * x, axis=1)[None, :]
     d = jnp.maximum(qn - 2.0 * dots + xn, 0.0)  # (BQ, BN)
 
-    j = pl.program_id(1)
     base = j * BN
-    # mask padded catalog rows so they never displace real candidates
+    # the last block may run past the catalog's end (the grid is ragged,
+    # the catalog is never padded): those rows hold unspecified values and
+    # must never displace real candidates
     gcol = base + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    d = jnp.where(gcol >= n_valid, _INF, d)
+    d = jnp.where(gcol >= n, _INF, d)
     if m_ref is not None:
         # additive row penalty (0 = live, +inf = tombstoned): the
         # mutable-catalog validity mask of DESIGN.md §10
         d = d + m_ref[...]
 
-    od_ref[...], oi_ref[...] = extract_block_topk(d, base, k)
+    od_ref[...], oi_ref[...] = merge_block_topk(
+        od_ref[...], oi_ref[...], d.T, base, axis=0)
 
 
 def l2_topk_pallas(
-    q: jax.Array, x: jax.Array, k: int, *, n_valid: int | None = None,
+    q: jax.Array, x: jax.Array, k: int, *,
     mask: jax.Array | None = None, interpret: bool = False
 ):
-    """Returns per-block partial results (Q, nblocks*k) dists + global ids.
+    """Returns (dists (k, Q), ids (k, Q)): per query (column), the k
+    smallest squared distances, ascending, and their catalog rows.
 
-    Callers merge with lax.top_k (see ops.topk_l2).  `n_valid` marks the
-    number of real catalog rows (the rest are padding).  `mask` is an
-    optional (1, N) float32 additive row penalty — 0 for live rows, +inf
-    for tombstoned ones — so a mutable catalog's removed rows can never
-    displace real candidates (their partial dists come back +inf and the
-    merge turns them into id = -1)."""
+    Q must be a multiple of BQ; N need not be a multiple of BN — the
+    catalog (which may be gigabytes) is read in place, ceil(N / BN) tiles
+    per query block, the ragged tail masked inside the kernel.  `mask` is
+    an optional (1, ceil(N / BN) * BN) float32 additive row penalty — 0
+    for live rows, +inf for tombstoned ones — so a mutable catalog's
+    removed rows can never displace real candidates (slots no live row
+    fills come back +inf; ops.topk_l2 turns their ids into -1)."""
     qq, d = q.shape
     n, _ = x.shape
-    assert qq % BQ == 0 and n % BN == 0 and k <= BN
+    assert qq % BQ == 0, qq
+    nb = pl.cdiv(n, BN)
     if mask is not None:
-        assert mask.shape == (1, n), (mask.shape, n)
-    grid = (qq // BQ, n // BN)
-    nb = n // BN
+        assert mask.shape == (1, nb * BN), (mask.shape, n)
     in_specs = [
         pl.BlockSpec((BQ, d), lambda i, j: (i, 0)),
         pl.BlockSpec((BN, d), lambda i, j: (j, 0)),
@@ -101,17 +134,20 @@ def l2_topk_pallas(
         in_specs.append(pl.BlockSpec((1, BN), lambda i, j: (0, j)))
         args = (q, x, mask.astype(jnp.float32))
     return pl.pallas_call(
-        functools.partial(_l2_topk_kernel, k,
-                          n if n_valid is None else n_valid, mask is not None),
-        grid=grid,
+        functools.partial(_l2_topk_kernel, n, mask is not None),
+        grid=(qq // BQ, nb),
         in_specs=in_specs,
+        # one output block per query block, resident across the catalog
+        # axis: it is the running top-k
         out_specs=[
-            pl.BlockSpec((BQ, k), lambda i, j: (i, j)),
-            pl.BlockSpec((BQ, k), lambda i, j: (i, j)),
+            pl.BlockSpec((k, BQ), lambda i, j: (0, i)),
+            pl.BlockSpec((k, BQ), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((qq, nb * k), jnp.float32),
-            jax.ShapeDtypeStruct((qq, nb * k), jnp.int32),
+            jax.ShapeDtypeStruct((k, qq), jnp.float32),
+            jax.ShapeDtypeStruct((k, qq), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

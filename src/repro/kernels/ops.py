@@ -11,6 +11,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ivf_scan as ivf_scan_kernel
 from repro.kernels import l2 as l2_kernel
@@ -96,27 +97,25 @@ def topk_l2(q: jax.Array, x: jax.Array, k: int, *, valid: jax.Array | None = Non
     Returns:
       (dists (Q, k), ids (Q, k)): the k smallest squared distances per
       query, ascending, with int32 catalog row ids.  Each (BQ, BN) tile
-      emits its k best by iterative masked-min extraction and the wrapper
-      merges the (Q, nblocks·k) partials with one `lax.top_k`
-      (DESIGN.md §3) — HBM traffic is Q·N·k/BN floats instead of Q·N.
+      folds its distances into a running top-k held in VMEM by iterative
+      masked-min extraction (DESIGN.md §3): the catalog is read once and
+      only Q·k results are written.
     """
     interp = (not _on_tpu()) if interpret is None else interpret
-    qq, n = q.shape[0], x.shape[0]
+    qq = q.shape[0]
     qp = _pad_rows(q, l2_topk_kernel.BQ)
-    xp = _pad_rows(x, l2_topk_kernel.BN)
     mask = None
     if valid is not None:
-        # additive per-row penalty: 0 live, +inf tombstoned (padded tail
-        # rows are already masked by n_valid inside the kernel)
-        mask = jnp.where(_pad_rows(valid[None, :].T, l2_topk_kernel.BN,
-                                   value=False).T, 0.0, jnp.inf)
-    pd, pi = l2_topk_kernel.l2_topk_pallas(qp, xp, k, n_valid=n, mask=mask,
-                                           interpret=interp)
-    neg, pos = jax.lax.top_k(-pd, k)
-    ids = jnp.take_along_axis(pi, pos, axis=1)
+        # additive per-row penalty: 0 live, +inf tombstoned (the ragged
+        # tail block's rows past N are masked inside the kernel)
+        mask = jnp.where(_pad_rows(valid, l2_topk_kernel.BN, value=False),
+                         0.0, jnp.inf)[None, :]
+    dists, ids = l2_topk_kernel.l2_topk_pallas(qp, x, k, mask=mask,
+                                              interpret=interp)
+    dists, ids = dists.T[:qq], ids.T[:qq]
     if valid is not None:
-        ids = jnp.where(jnp.isfinite(neg), ids, -1)
-    return (-neg)[:qq], ids[:qq]
+        ids = jnp.where(jnp.isfinite(dists), ids, -1)
+    return dists, ids
 
 
 @partial(jax.jit, static_argnames=("k", "chunk"))
@@ -156,7 +155,8 @@ def topk_l2_chunked(q: jax.Array, x: jax.Array, k: int, chunk: int,
         best_d, best_i = carry
         blk = jax.lax.dynamic_slice_in_dim(xp, j * chunk, chunk, 0)
         cn = jnp.sum(blk * blk, axis=1)[None, :]
-        d2 = jnp.maximum(qn - 2.0 * q @ blk.T + cn, 0.0)
+        dots = jnp.matmul(q, blk.T, precision=jax.lax.Precision.HIGHEST)
+        d2 = jnp.maximum(qn - 2.0 * dots + cn, 0.0)
         ids = j * chunk + jnp.arange(chunk, dtype=jnp.int32)[None, :]
         d2 = jnp.where(ids < n, d2, jnp.inf)                 # padded tail
         if vp is not None:                                   # tombstones
@@ -192,28 +192,28 @@ def ivf_scan_topk(q: jax.Array, x: jax.Array, cand: jax.Array, k: int, *,
     removed object can never surface from a stale inverted list or
     bucket — no kernel change, one extra gather + where.
 
-    The fused kernel's per-block extraction handles k up to its tile width
-    (BP = 128); larger k falls back to the XLA reference, which has no
-    such limit.
+    The kernel keeps a running top-k of any width k; the catalog stays in
+    HBM and only the candidate rows are fetched (DESIGN.md §4).
     """
     if valid is not None:
         cand = jnp.where(
             (cand >= 0) & valid[jnp.clip(cand, 0, x.shape[0] - 1)], cand, -1)
-    if k > ivf_scan_kernel.BP:
-        return ref.ivf_scan_ref(q, x, cand, k)
     interp = (not _on_tpu()) if interpret is None else interpret
+    if interp:
+        # the kernel DMAs by hand: off the TPU it needs the TPU
+        # interpreter, which models HBM, SMEM and DMA semaphores
+        interp = pltpu.InterpretParams()
     b, p = cand.shape
     qp = _pad_rows(q, ivf_scan_kernel.BQ)
     cp = _pad_rows(cand, ivf_scan_kernel.BQ, value=-1)
     padp = (-p) % ivf_scan_kernel.BP
     if padp:
         cp = jnp.pad(cp, ((0, 0), (0, padp)), constant_values=-1)
-    pd, pi = ivf_scan_kernel.ivf_scan_pallas(qp, x, cp, k, interpret=interp)
-    neg, pos = jax.lax.top_k(-pd, k)
-    ppos = jnp.take_along_axis(pi, pos, axis=1)          # positions in P axis
-    ids = jnp.take_along_axis(cp, ppos, axis=1)
-    ids = jnp.where(jnp.isfinite(neg), ids, -1)
-    return (-neg)[:b], ids[:b]
+    dists, pos = ivf_scan_kernel.ivf_scan_pallas(qp, x, cp, k,
+                                                 interpret=interp)
+    ids = jnp.take_along_axis(cp, pos, axis=1)
+    ids = jnp.where(jnp.isfinite(dists), ids, -1)
+    return dists[:b], ids[:b]
 
 
 # jnp fallbacks, exported for benchmarking kernel vs XLA-fused baseline.

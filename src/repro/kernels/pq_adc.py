@@ -14,9 +14,12 @@ is idle during a scan anyway, so the extra FLOPs are free and the kernel
 stays HBM-bandwidth-bound on the (N, M) uint8 code stream, which is the
 same bottleneck (and byte count) as the GPU original.
 
-Blocks: (BQ=128 queries) x (BN=128 points); the full (BQ, M, 256) LUT tile
-lives in VMEM (M=16, fp32 -> 2 MiB).  The M-loop is a fori_loop inside the
-kernel so codes are touched once.
+Blocks: (BQ=128 queries) x (BN=128 points).  The wrapper hands the kernel
+subspace-major layouts — the LUT as (M, Q, C) and the codes as (M, N) — so
+subspace m is a leading-axis index of the (M, BQ, C) LUT tile and one
+sublane row of the (M, BN) code tile: the TPU lowers neither a dynamic
+lane slice nor a gather.  The M-loop is unrolled (M is static and small,
+16 for a 128-d catalog), so codes are touched once.
 """
 
 from __future__ import annotations
@@ -27,25 +30,22 @@ from jax.experimental import pallas as pl
 
 BQ = 128
 BN = 128
+_HIGHEST = jax.lax.Precision.HIGHEST  # the LUT's f32 values, not bf16
 
 
 def _adc_kernel(lut_ref, codes_ref, o_ref):
-    lut = lut_ref[...]          # (BQ, M, C) float32
-    codes = codes_ref[...]      # (BN, M) int32
-    m = lut.shape[1]
-    c = lut.shape[2]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], c), 1)
-
-    def body(mi, acc):
-        onehot = (cols == codes[:, mi][:, None]).astype(jnp.float32)  # (BN, C)
+    m, _, c = lut_ref.shape     # (M, BQ, C) float32
+    bn = codes_ref.shape[1]     # (M, BN) int32
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, bn), 0)
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for mi in range(m):
+        onehot = (rows == codes_ref[mi:mi + 1, :]).astype(jnp.float32)
         # (BQ, C) @ (C, BN) on the MXU
-        return acc + jax.lax.dot_general(
-            lut[:, mi, :], onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        acc = acc + jax.lax.dot_general(
+            lut_ref[mi], onehot, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_HIGHEST,
         )
-
-    acc = jnp.zeros((lut.shape[0], codes.shape[0]), jnp.float32)
-    o_ref[...] = jax.lax.fori_loop(0, m, body, acc)
+    o_ref[...] = acc
 
 
 def pq_adc_pallas(
@@ -61,10 +61,10 @@ def pq_adc_pallas(
         _adc_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BQ, m, c), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((BN, m), lambda i, j: (j, 0)),
+            pl.BlockSpec((m, BQ, c), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((m, BN), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((BQ, BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.float32),
         interpret=interpret,
-    )(lut, codes.astype(jnp.int32))
+    )(jnp.transpose(lut, (1, 0, 2)), codes.astype(jnp.int32).T)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -11,7 +12,8 @@ def pairwise_l2_ref(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
     xn = jnp.sum(x * x, axis=-1)[None, :]
-    return jnp.maximum(qn - 2.0 * (q @ x.T) + xn, 0.0)
+    dots = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(qn - 2.0 * dots + xn, 0.0)
 
 
 def pq_adc_ref(lut: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
@@ -24,7 +26,8 @@ def pq_adc_ref(lut: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
     q, m, c = lut.shape
     onehot = jnp.equal(codes[..., None], jnp.arange(c)[None, None, :])
     return jnp.einsum("qmc,nmc->qn", lut.astype(jnp.float32),
-                      onehot.astype(jnp.float32))
+                      onehot.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def l2_topk_ref(q: jnp.ndarray, x: jnp.ndarray, k: int, valid=None):
@@ -35,8 +38,6 @@ def l2_topk_ref(q: jnp.ndarray, x: jnp.ndarray, k: int, valid=None):
     fewer than k live rows underflow as dist = +inf, id = -1.  With
     valid=None the output is exactly the unmasked scan (no -1s possible).
     """
-    import jax
-
     d = pairwise_l2_ref(q, x)
     if valid is None:
         neg, idx = jax.lax.top_k(-d, k)
@@ -60,8 +61,6 @@ def ivf_scan_ref(q: jnp.ndarray, x: jnp.ndarray, cand: jnp.ndarray, k: int,
     §10): candidate ids whose row is tombstoned are treated as -1 slots,
     so a removed object can never surface from a stale inverted list.
     """
-    import jax
-
     q = q.astype(jnp.float32)
     if valid is not None:
         cand = jnp.where(

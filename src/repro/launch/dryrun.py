@@ -243,17 +243,13 @@ def acai_cell_meta(mesh_kind: str, *, n_catalog: int, d: int, batch: int,
     """Static provenance fields of the AÇAI retrieval cell, kept in a
     helper so tests can pin the record schema without compiling on a
     512-device mesh (tests/test_policy_api.py)."""
-    from repro.compat import SHARD_MAP_IMPL
     from repro.core.policy_api import PolicySpec
 
     return {"arch": "acai-retrieval", "shape": f"retrieval_b{batch}",
             "mesh": mesh_kind, "kind": "serve", "variant": variant,
             "seq_len": n_catalog, "global_batch": batch,
             "params_total": n_catalog * d, "params_active": n_catalog * d,
-            # which shard_map the compat shim resolved (provenance: the
-            # cell lowers on both the jax.shard_map and the experimental
-            # API — see repro/compat.py)
-            "shard_map_impl": SHARD_MAP_IMPL,
+            "shard_map_impl": "jax.shard_map",
             # index selection provenance (DESIGN.md §8): the cell lowers
             # the exact per-shard scan — 'exact' is the spec-less
             # perfect-recall configuration, same convention as

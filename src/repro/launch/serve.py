@@ -72,7 +72,6 @@ load is set as a fraction of the deterministic service model's capacity
 
 from __future__ import annotations
 
-import os
 import sys
 
 def _sniff_mesh_shards(argv):
@@ -93,13 +92,12 @@ def _sniff_mesh_shards(argv):
     return 0
 
 
-if __name__ == "__main__":  # before ANY jax import; no import side effects
+if __name__ == "__main__":  # before jax starts a backend
+    from repro.launch.mesh import cpu_host_devices
+
     _p = _sniff_mesh_shards(sys.argv)
     if _p > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_p} "
-            + os.environ.get("XLA_FLAGS", "")
-        )
+        cpu_host_devices(_p)
 
 import argparse  # noqa: E402
 import time      # noqa: E402
@@ -113,6 +111,8 @@ from repro.core.policy_api import (PolicySpec, parse_policy_opts,
                                    registered_policies)
 from repro.index.base import (IndexSpec, parse_index_opts,
                               registered_backends)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh, shard_devices
 from repro.models import init_params
 from repro.serve import SemanticCachedLM, ServeEngine, generate
 from repro.serve.arrivals import ARRIVAL_KINDS
@@ -214,6 +214,7 @@ def main():
     res.add_argument("--retries", type=int, default=None,
                      help="extra attempts after the first (default 2)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     try:
         policy_spec = PolicySpec(args.policy,
@@ -320,13 +321,14 @@ def main():
 
     mesh = None
     if args.mesh_shards > 1:
-        if jax.device_count() < args.mesh_shards:
-            raise SystemExit(
-                f"--mesh-shards {args.mesh_shards} needs that many devices "
-                f"(have {jax.device_count()})")
+        try:
+            devices = shard_devices(args.mesh_shards)
+        except ValueError as e:
+            raise SystemExit(f"--mesh-shards: {e}")
         if args.catalog % args.mesh_shards:
             raise SystemExit("--catalog must divide by --mesh-shards")
-        mesh = jax.make_mesh((1, args.mesh_shards), ("data", "model"))
+        mesh = make_mesh((1, args.mesh_shards), ("data", "model"),
+                         devices=devices)
 
     cfg = (SMOKE_ARCHS if args.smoke else ARCHS)[args.arch]
     if not cfg.has_decode:

@@ -194,7 +194,7 @@ def _moe_shard_map(p, xf, cfg: ModelConfig):
         aux = jax.lax.pmean(aux, batch_ax)
         return out_l, aux
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     fs = "data" if fsdp else None
     mapped = shard_map(

@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import SMOKE_ARCHS
 from repro.configs.shapes import ShapeSpec
+from repro.launch.mesh import make_mesh
 from repro.train import OptConfig, init_train_state, make_train_step
 from repro.train import checkpoint
 from repro.train.batching import synthetic_batch
@@ -98,7 +99,7 @@ def test_elastic_restore_changes_placement(tmp_path, small_state):
     from jax.sharding import NamedSharding, PartitionSpec as P
     cfg, state = small_state
     checkpoint.save(str(tmp_path), 3, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     restored = checkpoint.restore(str(tmp_path), 3, state, shardings)
     _trees_equal(state, restored)

@@ -19,6 +19,7 @@ from repro.core.distributed import (build_sharded_ivf, collectives_per_step,
                                     make_mutable_step_sharded,
                                     make_retrieval_step, make_step_sharded)
 from repro.core.oma import OMAConfig
+from repro.launch.mesh import make_mesh
 
 N, D, B = 256, 8, 8
 
@@ -35,7 +36,7 @@ def catalog():
 
 
 def _mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _static_counts(cfg, catalog, mesh, **kw):

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 _CHILD = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -23,6 +25,7 @@ _CHILD = textwrap.dedent("""
     from repro.core.distributed import (build_sharded_ivf,
                                         make_replay_sharded,
                                         make_retrieval_step, reference_step)
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     N, d, B, C, k, h = 512, 16, 8, 24, 4, 32
@@ -30,7 +33,7 @@ _CHILD = textwrap.dedent("""
     y0 = jnp.full((N,), h / N, jnp.float32)
     reqs = jnp.asarray(rng.normal(size=(B, d)), jnp.float32)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     kw = dict(n_shard=N // 4, d=d, c=C, k=k, c_f=1.0, h=h, eta=0.05,
               top_a=h + 16, batch_axes=("data",))
     step = make_retrieval_step(mesh, **kw)
@@ -137,7 +140,7 @@ def test_sharded_replay_bit_consistent_on_1device_mesh():
     a = 2 * 48 + 64
     cfg = policy.AcaiConfig(h=48, k=8, c_f=1.0, c_remote=32, c_local=16,
                             oma=oma.OMAConfig(eta=0.05, projection_topk=a))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     fnb = policy.exact_candidate_fn_batched(cat, cfg.c_remote, cfg.c_local)
     s0 = policy.init_state(cat.shape[0], cfg)
     for b in (1, 8):
@@ -161,7 +164,7 @@ def test_acai_cache_mesh_serving_path():
     cat, reqs = jnp.array(catalog), jnp.array(reqs)
     cfg = policy.AcaiConfig(h=32, k=4, c_f=1.0, c_remote=16, c_local=8,
                             oma=oma.OMAConfig(eta=0.05))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cache = policy.AcaiCache(cat, cfg, seed=0, mesh=mesh)
     m1 = cache.serve_update(reqs[0])
     assert m1.gain_int.shape == ()
@@ -169,3 +172,31 @@ def test_acai_cache_mesh_serving_path():
     assert mb.gain_int.shape == (8,)
     assert int(cache.state.t) == 9
     assert abs(float(jnp.sum(cache.state.y)) - cfg.h) < 1e-2
+
+
+@pytest.mark.parametrize("where", ["device0", "mesh4"])
+def test_second_batch_reuses_compiled_step(where, multi_device):
+    """A catalog big enough to reach the step as an argument (committed to
+    one device, or split over a 4-shard mesh): the cache state starts
+    where the step returns it, so batch 2 runs batch 1's program."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core import oma, policy, trace
+
+    catalog, reqs, _ = trace.sift_like(n=1 << 15, d=16, t=32, seed=1)
+    assert catalog.nbytes >= policy.ARG_BYTES
+    cfg = policy.AcaiConfig(h=32, k=4, c_f=1.0, c_remote=16, c_local=8,
+                            oma=oma.OMAConfig(eta=0.05))
+    if where == "mesh4":
+        mesh = make_mesh((1, 4), ("data", "model"),
+                         devices=jax.devices()[:4])
+        cat = jax.device_put(catalog,
+                             NamedSharding(mesh, PartitionSpec("model")))
+        cache = policy.AcaiCache(cat, cfg, seed=0, mesh=mesh,
+                                 sharded_kwargs={"scan_chunk": 4096})
+    else:
+        cat = jax.device_put(catalog, jax.devices()[0])
+        cache = policy.AcaiCache(cat, cfg, seed=0)
+    for s in (0, 8, 16):
+        cache.serve_update_batch(jnp.asarray(reqs[s:s + 8]))
+    assert cache._bsteps[8]._cache_size() == 1

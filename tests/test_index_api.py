@@ -12,6 +12,7 @@ from repro.core import oma, policy, trace
 from repro.index import (Index, IndexSpec, build_index, parse_index_opts,
                          registered_backends)
 from repro.index.candidates import index_candidate_fn_batched
+from repro.launch.mesh import make_mesh
 
 # tiny-catalog build kwargs: every single-device backend, seconds to
 # build — the canonical table lives in base.py (shared with smoke.sh)
@@ -112,7 +113,7 @@ def test_sharded_twin_through_registry(setup):
     from repro.core.distributed import ShardedIVF, make_replay_sharded
 
     cat, rq, cfg = setup
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ivf = build_index(IndexSpec("ivf_sharded", {"nlist": 8, "nprobe": 4}),
                       cat, mesh=mesh)
     assert isinstance(ivf, ShardedIVF)
@@ -281,7 +282,7 @@ def test_local_overflow_counter_sharded(setup):
     state = policy.CacheState(
         y=jnp.full((n,), cfg.h / n), x=x,
         t=jnp.zeros((), jnp.int32), key=jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     _, m = make_step_sharded(cfg_dbg, mesh, cat, 4, scan_chunk=64)(
         state, rq[:4])
     np.testing.assert_array_equal(np.asarray(m.local_overflow),

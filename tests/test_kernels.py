@@ -36,6 +36,23 @@ def test_topk_l2(q, n, d, k):
     np.testing.assert_allclose(d_of_ids, np.array(wd), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("q,n,k,live", [(6, 300, 8, 0.5), (3, 129, 16, 0.05)])
+def test_topk_l2_tombstones(q, n, k, live):
+    """The running top-k with a tombstone mask (interpret mode) vs the
+    masked oracle: removed rows never surface, and queries with fewer
+    than k live rows underflow as (+inf, -1) in the same slots."""
+    rng = np.random.default_rng(6)
+    qa = jnp.array(rng.normal(size=(q, 16)).astype(np.float32))
+    xa = jnp.array(rng.normal(size=(n, 16)).astype(np.float32))
+    valid = jnp.array(rng.random(n) < live)
+    gd, gi = ops.topk_l2(qa, xa, k, valid=valid)
+    wd, wi = ref.l2_topk_ref(qa, xa, k, valid)
+    np.testing.assert_allclose(np.array(gd), np.array(wd), rtol=1e-4, atol=1e-4)
+    got_i, want_i = np.array(gi), np.array(wi)
+    np.testing.assert_array_equal(got_i == -1, want_i == -1)
+    assert np.array(valid)[got_i[got_i >= 0]].all()
+
+
 @pytest.mark.parametrize("q,n,m,c", [(2, 64, 4, 16), (128, 300, 8, 256),
                                      (5, 1000, 16, 256), (1, 50, 2, 4)])
 def test_pq_adc(q, n, m, c):
@@ -48,7 +65,8 @@ def test_pq_adc(q, n, m, c):
 
 
 @pytest.mark.parametrize("b,n,p,d,k", [(4, 200, 64, 16, 8), (5, 300, 37, 16, 8),
-                                       (12, 500, 130, 32, 16), (1, 100, 9, 8, 4)])
+                                       (12, 500, 130, 32, 16), (1, 100, 9, 8, 4),
+                                       (3, 400, 300, 8, 150)])
 def test_ivf_scan(b, n, p, d, k):
     """Fused gather+L2+top-k (interpret mode) vs the XLA oracle, with
     padded lists and -1 sentinels."""

@@ -14,6 +14,7 @@ from repro.core import baselines as B
 from repro.core import churn, oma, policy, trace
 from repro.core import policy_api as PA
 from repro.core.costs import CostModel
+from repro.launch.mesh import make_mesh
 from repro.index import Index, IndexSpec, build_index
 from repro.index.base import TINY_BUILD_KWARGS as TINY
 from repro.index.base import grow_capacity, slab_append
@@ -190,7 +191,7 @@ def test_acai_cache_mutation_guards(setup, cache_cfg):
     # only configurations the exact masked scan cannot honor still reject:
     # approximate sharded structures (ivf / scan_chunk) have no mutable
     # sharded serving path
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sharded = policy.AcaiCache(cat, cache_cfg, mesh=mesh, seed=0,
                                sharded_kwargs={"scan_chunk": 64})
     with pytest.raises(NotImplementedError, match="sharded"):

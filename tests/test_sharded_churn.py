@@ -19,6 +19,7 @@ from repro.core import churn, policy, trace
 from repro.core.distributed import (make_mutable_step_sharded, owner_shard,
                                     route_ids_by_owner, sharded_slab_append)
 from repro.core.oma import OMAConfig
+from repro.launch.mesh import make_mesh
 
 D = 8
 
@@ -33,7 +34,7 @@ def cfg():
 
 
 def _mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _rolling():

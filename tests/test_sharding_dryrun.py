@@ -59,6 +59,7 @@ _CHILD = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.configs import SMOKE_ARCHS
     from repro.launch.mesh import mesh_shape_dict
+    from repro.launch.mesh import make_mesh
     from repro.models import init_params
     from repro.sharding import specs as S
     from repro.sharding.ctx import mesh_context
@@ -68,7 +69,7 @@ _CHILD = textwrap.dedent("""
     from repro.configs.shapes import ShapeSpec
 
     cfg = SMOKE_ARCHS["mixtral-8x22b"]
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     msd = mesh_shape_dict(mesh)
     params = init_params(jax.random.PRNGKey(0), cfg)
     pspecs = S.param_pspecs(cfg, jax.eval_shape(lambda: init_params(
