@@ -3,10 +3,7 @@
 
 Default: reduced CPU-friendly sizes (minutes).  ``--full`` = paper-scale.
 ``--suite NAME`` runs a single harness; ``--list`` prints every
-registered suite, experiment grid, policy and trace scenario.  The
-roofline/dry-run analyses are separate (``python -m benchmarks.roofline``
-after ``launch/dryrun.py``) since they operate on compiled artifacts, not
-wall time.
+registered suite, experiment grid, policy and trace scenario.
 
 The fig1..fig8 suites are thin wrappers over named grids of the
 config-driven experiment harness (``benchmarks/experiments.py``); the

@@ -129,22 +129,26 @@ def exact_candidate_fn_batched(
 
     def fn(rs: jax.Array, x: jax.Array):
         b = rs.shape[0]
-        d_full = pairwise_dissimilarity(rs, catalog, metric)     # (B, N)
-        _, ids_remote = jax.lax.top_k(-d_full, c_remote)
-        d_cached = jnp.where(x[None, :] > 0.5, d_full, jnp.inf)
-        _, ids_local = jax.lax.top_k(-d_cached, c_local)
-        ids = jnp.concatenate([ids_remote, ids_local], axis=1)
-        valid = dedup_mask_batched(ids, n)
-        # a "local" candidate slot is only valid if that object is cached
-        cached_ok = jnp.concatenate(
-            [jnp.ones((b, c_remote), bool), x[ids_local] > 0.5], axis=1
-        )
-        valid = valid & cached_ok
-        d = jnp.where(
-            valid,
-            jnp.take_along_axis(d_full, jnp.clip(ids, 0, n - 1), axis=1),
-            BIG_COST,
-        )
+        with jax.named_scope("acai.remote"):
+            d_full = pairwise_dissimilarity(rs, catalog, metric)  # (B, N)
+            _, ids_remote = jax.lax.top_k(-d_full, c_remote)
+        with jax.named_scope("acai.local"):
+            d_cached = jnp.where(x[None, :] > 0.5, d_full, jnp.inf)
+            _, ids_local = jax.lax.top_k(-d_cached, c_local)
+        with jax.named_scope("acai.merge"):
+            ids = jnp.concatenate([ids_remote, ids_local], axis=1)
+            valid = dedup_mask_batched(ids, n)
+            # a "local" candidate slot is only valid if that object is
+            # cached
+            cached_ok = jnp.concatenate(
+                [jnp.ones((b, c_remote), bool), x[ids_local] > 0.5], axis=1
+            )
+            valid = valid & cached_ok
+            d = jnp.where(
+                valid,
+                jnp.take_along_axis(d_full, jnp.clip(ids, 0, n - 1), axis=1),
+                BIG_COST,
+            )
         return ids, d, valid
 
     return fn
@@ -328,6 +332,7 @@ def make_replay(cfg: AcaiConfig, candidate_fn: Callable) -> Callable:
     return jit_closed_over_as_args(replay)
 
 
+@jax.named_scope("acai.rounding")
 def finish_step_batched(cfg_up: AcaiConfig, state: CacheState, key, k_round,
                         batch: int, y_new, gain_int, gain_frac, cost,
                         served_local, local_overflow=None):
@@ -407,26 +412,28 @@ def apply_candidates_batched(cfg: AcaiConfig, cfg_up: AcaiConfig,
     """
     key, k_round = jax.random.split(state.key)
     n = state.y.shape[0]
-    ids_c = jnp.clip(ids, None, n - 1)
-    x_cand = jnp.where(valid, state.x[ids_c], 0.0)
-    y_cand = jnp.where(valid, state.y[ids_c], 0.0)
+    with jax.named_scope("acai.gain"):
+        ids_c = jnp.clip(ids, None, n - 1)
+        x_cand = jnp.where(valid, state.x[ids_c], 0.0)
+        y_cand = jnp.where(valid, state.y[ids_c], 0.0)
 
-    served = gain_lib.serve_batch(d, x_cand, cfg.k, cfg.c_f)
-    gain_frac, g_cand = gain_lib.gain_and_subgradient_batch(
-        d, y_cand, cfg.k, cfg.c_f
-    )
+        served = gain_lib.serve_batch(d, x_cand, cfg.k, cfg.c_f)
+        gain_frac, g_cand = gain_lib.gain_and_subgradient_batch(
+            d, y_cand, cfg.k, cfg.c_f
+        )
 
-    g_full = (
-        jnp.zeros_like(state.y)
-        .at[ids_c.reshape(-1)]
-        .add(jnp.where(valid, g_cand, 0.0).reshape(-1) / batch)
-    )
-    y_new = oma_lib.oma_update(state.y, g_full, cfg.h, cfg_up.oma)
-    if alive is not None:
-        # invalidation invariant: no fractional mass on dead rows (the
-        # Y_FLOOR clip would otherwise resurrect them with 1e-12 mass,
-        # and rounding could then physically cache a removed object)
-        y_new = jnp.where(alive, y_new, 0.0)
+        g_full = (
+            jnp.zeros_like(state.y)
+            .at[ids_c.reshape(-1)]
+            .add(jnp.where(valid, g_cand, 0.0).reshape(-1) / batch)
+        )
+    with jax.named_scope("acai.oma"):
+        y_new = oma_lib.oma_update(state.y, g_full, cfg.h, cfg_up.oma)
+        if alive is not None:
+            # invalidation invariant: no fractional mass on dead rows (the
+            # Y_FLOOR clip would otherwise resurrect them with 1e-12 mass,
+            # and rounding could then physically cache a removed object)
+            y_new = jnp.where(alive, y_new, 0.0)
     return finish_step_batched(
         cfg_up, state, key, k_round, batch, y_new, served.gain,
         gain_frac, served.cost,
@@ -641,6 +648,8 @@ class AcaiCache:
         self.index = None  # the spec-built index (None = exact/escape hatch)
         self._sharded_kwargs = dict(sharded_kwargs or {})
         self._bsteps: dict[int, Callable] = {}
+        # serve_update_batch calls so far: the `call` of its profiler spans
+        self._calls = 0
         # mutable-catalog bookkeeping (DESIGN.md §10): the cache starts on
         # the static jitted path and flips to the mutable two-stage path
         # (eager candidates + jitted apply tail) on the first add/remove.
@@ -828,14 +837,25 @@ class AcaiCache:
         in two stages (eager candidate slab against the live structures +
         the jitted `make_mutable_step` tail).  With a RemoteBackend
         attached (`attach_remote`), the batch routes through the
-        resilience ladder instead (DESIGN.md §11)."""
+        resilience ladder instead (DESIGN.md §11).
+
+        Under `jax.profiler` the call shows as three sibling host spans,
+        each carrying the same `call` number: `acai.put` (the batch to the
+        device), `acai.finite_check` (read back and tested for NaN/Inf)
+        and `acai.step` (the step enqueued).  With the profiler off they
+        cost a few microseconds."""
         from repro.index.base import check_finite_queries
 
-        rs = jnp.atleast_2d(rs)
-        check_finite_queries(rs, "AcaiCache.serve_update_batch")
-        if self._res is not None:
-            return self._res.serve_update_batch(rs)
-        return self._serve_batch_direct(rs)
+        self._calls += 1
+        span = partial(jax.profiler.TraceAnnotation, call=self._calls)
+        with span("acai.put"):
+            rs = jnp.atleast_2d(rs)
+        with span("acai.finite_check"):
+            check_finite_queries(rs, "AcaiCache.serve_update_batch")
+        with span("acai.step"):
+            if self._res is not None:
+                return self._res.serve_update_batch(rs)
+            return self._serve_batch_direct(rs)
 
     def _serve_batch_direct(self, rs: jax.Array) -> StepMetrics:
         """The fault-oblivious serving step (also the all-ok fast path of

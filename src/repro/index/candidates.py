@@ -85,36 +85,38 @@ def index_candidate_fn_batched(
     rerank = not getattr(index, "exact_distances", False)
 
     def fn(rs: jax.Array, x: jax.Array):
-        b = rs.shape[0]
-        d_remote, ids_remote = index.query(rs, c_remote)     # (B, c_remote)
-        if rerank:
-            # single exact re-rank of the retrieved candidates (fused
-            # scan); -1 misses stay -1 (dist = +inf) through the scan.
-            d_remote, ids_remote = ops.ivf_scan_auto(
-                rs, catalog, ids_remote, c_remote
-            )
-        rmiss = ids_remote < 0
-        ids_remote = jnp.where(rmiss, n, ids_remote)         # n = invalid
-        d_remote = jnp.where(rmiss, BIG_COST, d_remote)
+        with jax.named_scope("acai.remote"):
+            d_remote, ids_remote = index.query(rs, c_remote)  # (B, c_remote)
+            if rerank:
+                # single exact re-rank of the retrieved candidates (fused
+                # scan); -1 misses stay -1 (dist = +inf) through the scan.
+                d_remote, ids_remote = ops.ivf_scan_auto(
+                    rs, catalog, ids_remote, c_remote
+                )
+            rmiss = ids_remote < 0
+            ids_remote = jnp.where(rmiss, n, ids_remote)      # n = invalid
+            d_remote = jnp.where(rmiss, BIG_COST, d_remote)
 
         # local side: gather the cached rows *once* (shared across the
         # batch), then one (B, cap) distance GEMM + top-k — never an (N,)
         # distance scan, and no per-query duplicate of the cached slab.
-        cached = jnp.nonzero(x > 0.5, size=cap, fill_value=-1)[0]  # (cap,)
-        cached_embs = catalog[jnp.clip(cached, 0, n - 1)]          # (cap, d)
-        d_loc = ops.pairwise_l2_xla(rs, cached_embs)               # (B, cap)
-        d_loc = jnp.where((cached >= 0)[None, :], d_loc, jnp.inf)
-        neg, pos = jax.lax.top_k(-d_loc, c_local)
-        ids_local = jnp.where(jnp.isfinite(neg), cached[pos], -1)
-        d_local = -neg
-        lmiss = ids_local < 0
-        ids_local = jnp.where(lmiss, n, ids_local)
-        d_local = jnp.where(lmiss, BIG_COST, d_local)
+        with jax.named_scope("acai.local"):
+            cached = jnp.nonzero(x > 0.5, size=cap, fill_value=-1)[0]
+            cached_embs = catalog[jnp.clip(cached, 0, n - 1)]  # (cap, d)
+            d_loc = ops.pairwise_l2_xla(rs, cached_embs)       # (B, cap)
+            d_loc = jnp.where((cached >= 0)[None, :], d_loc, jnp.inf)
+            neg, pos = jax.lax.top_k(-d_loc, c_local)
+            ids_local = jnp.where(jnp.isfinite(neg), cached[pos], -1)
+            d_local = -neg
+            lmiss = ids_local < 0
+            ids_local = jnp.where(lmiss, n, ids_local)
+            d_local = jnp.where(lmiss, BIG_COST, d_local)
 
-        ids = jnp.concatenate([ids_remote, ids_local], axis=1)
-        d = jnp.concatenate([d_remote, d_local], axis=1)
-        valid = dedup_mask_batched(ids, n)
-        d = jnp.where(valid, d, BIG_COST)
+        with jax.named_scope("acai.merge"):
+            ids = jnp.concatenate([ids_remote, ids_local], axis=1)
+            d = jnp.concatenate([d_remote, d_local], axis=1)
+            valid = dedup_mask_batched(ids, n)
+            d = jnp.where(valid, d, BIG_COST)
         return ids, d, valid
 
     fn.local_cap = cap  # static cached-row bound, read by the policy step
@@ -146,40 +148,43 @@ def _assemble_mutable_slab(rs, x, catalog, alive, ids_remote, d_remote,
     slab (ids, dists, valid) — same layout as index_candidate_fn_batched,
     with tombstoned rows resolved to invalid slots throughout."""
     n = catalog.shape[0]
-    b = rs.shape[0]
-    if rerank:
-        # exact re-rank of approximate-distance retrievals, tombstones
-        # folded to -1 inside the fused scan
-        d_remote, ids_remote = ops.ivf_scan_auto(
-            rs, catalog, ids_remote, c_remote, alive)
-    else:
-        # the index's own masking already excludes tombstones; belt and
-        # braces for foreign indexes that predate the mutation contract
-        dead = (ids_remote >= 0) & ~alive[jnp.clip(ids_remote, 0, n - 1)]
-        ids_remote = jnp.where(dead, -1, ids_remote)
-        d_remote = jnp.where(dead, jnp.inf, d_remote)
-    rmiss = ids_remote < 0
-    ids_remote = jnp.where(rmiss, n, ids_remote)             # n = invalid
-    d_remote = jnp.where(rmiss, BIG_COST, d_remote)
+    with jax.named_scope("acai.remote"):
+        if rerank:
+            # exact re-rank of approximate-distance retrievals, tombstones
+            # folded to -1 inside the fused scan
+            d_remote, ids_remote = ops.ivf_scan_auto(
+                rs, catalog, ids_remote, c_remote, alive)
+        else:
+            # the index's own masking already excludes tombstones; belt
+            # and braces for foreign indexes that predate the mutation
+            # contract
+            dead = (ids_remote >= 0) & ~alive[jnp.clip(ids_remote, 0, n - 1)]
+            ids_remote = jnp.where(dead, -1, ids_remote)
+            d_remote = jnp.where(dead, jnp.inf, d_remote)
+        rmiss = ids_remote < 0
+        ids_remote = jnp.where(rmiss, n, ids_remote)         # n = invalid
+        d_remote = jnp.where(rmiss, BIG_COST, d_remote)
 
     # local side: identical to the static generator — the x(dead) = 0
     # invalidation invariant keeps tombstoned rows out of the gather
-    cached = jnp.nonzero(x > 0.5, size=cap, fill_value=-1)[0]    # (cap,)
-    cached_embs = catalog[jnp.clip(cached, 0, n - 1)]            # (cap, d)
-    d_loc = ops.pairwise_l2_xla(rs, cached_embs)                 # (B, cap)
-    ok = (cached >= 0) & alive[jnp.clip(cached, 0, n - 1)]
-    d_loc = jnp.where(ok[None, :], d_loc, jnp.inf)
-    neg, pos = jax.lax.top_k(-d_loc, c_local)
-    ids_local = jnp.where(jnp.isfinite(neg), cached[pos], -1)
-    d_local = -neg
-    lmiss = ids_local < 0
-    ids_local = jnp.where(lmiss, n, ids_local)
-    d_local = jnp.where(lmiss, BIG_COST, d_local)
+    with jax.named_scope("acai.local"):
+        cached = jnp.nonzero(x > 0.5, size=cap, fill_value=-1)[0]  # (cap,)
+        cached_embs = catalog[jnp.clip(cached, 0, n - 1)]          # (cap, d)
+        d_loc = ops.pairwise_l2_xla(rs, cached_embs)               # (B, cap)
+        ok = (cached >= 0) & alive[jnp.clip(cached, 0, n - 1)]
+        d_loc = jnp.where(ok[None, :], d_loc, jnp.inf)
+        neg, pos = jax.lax.top_k(-d_loc, c_local)
+        ids_local = jnp.where(jnp.isfinite(neg), cached[pos], -1)
+        d_local = -neg
+        lmiss = ids_local < 0
+        ids_local = jnp.where(lmiss, n, ids_local)
+        d_local = jnp.where(lmiss, BIG_COST, d_local)
 
-    ids = jnp.concatenate([ids_remote, ids_local], axis=1)
-    d = jnp.concatenate([d_remote, d_local], axis=1)
-    valid = dedup_mask_batched(ids, n)
-    d = jnp.where(valid, d, BIG_COST)
+    with jax.named_scope("acai.merge"):
+        ids = jnp.concatenate([ids_remote, ids_local], axis=1)
+        d = jnp.concatenate([d_remote, d_local], axis=1)
+        valid = dedup_mask_batched(ids, n)
+        d = jnp.where(valid, d, BIG_COST)
     return ids, d, valid
 
 
