@@ -26,8 +26,11 @@ per shard) and IndexSpec("ivf_sharded"), then the same trace through the
 one-chip cache on devices[0] for comparison.
 
 Compile seconds and steady milliseconds per batch are printed per arm as
-smoke readings, not benchmark numbers.  Any failed check or phase exits
-non-zero; off a TPU the script exits non-zero before any work.  The last
+smoke readings, not benchmark numbers; the flat arm also prints how many
+selection passes `l2_topk` ran per catalog tile for one batch (at most
+c_remote; about one where the catalog is in no order relative to the
+requests).  Any failed check or phase exits non-zero; off a TPU the
+script exits non-zero before any work.  The last
 line of stdout is {"ok": true, "device": {...}} with the platform, device
 kind and device count as JAX reports them.
 
@@ -154,7 +157,7 @@ def one_chip(catalog: np.ndarray, reqs: np.ndarray) -> None:
     from repro.core import oma, policy
     from repro.core.costs import calibrate_fetch_cost
     from repro.index import IndexSpec
-    from repro.kernels import ops
+    from repro.kernels import l2_topk, ops
 
     catalog_dev = jax.device_put(catalog)
     c_f = float(calibrate_fetch_cost(catalog_dev))
@@ -186,6 +189,12 @@ def one_chip(catalog: np.ndarray, reqs: np.ndarray) -> None:
               f"({differ} of {SAMPLE} requests differ only by ties)")
     err = np.abs(np.asarray(d_flat, np.float64) - d_host).max()
     log(f"  flat f32 distances vs float64: max abs error {err:.3e}")
+    _, _, passes = ops.topk_l2(jnp.asarray(q[:BATCH]), catalog_dev, C_REMOTE,
+                               return_passes=True)
+    tiles = -(-N // l2_topk.BN)
+    log(f"  l2_topk selection passes per {l2_topk.BN}-row tile, {BATCH} "
+        f"requests, k = {C_REMOTE}: {int(passes[0]) / tiles:.3f} "
+        f"(at most {C_REMOTE})")
 
     spec = IndexSpec("ivf", {"nlist": NLIST, "nprobe": NPROBE})
     log(f"arm ivf: {spec} (Pallas ivf_scan, catalog in HBM)")

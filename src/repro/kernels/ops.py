@@ -79,9 +79,9 @@ def pq_adc(lut: jax.Array, codes: jax.Array, *, interpret: bool | None = None):
     return out[:qq, :n]
 
 
-@partial(jax.jit, static_argnames=("k", "interpret"))
+@partial(jax.jit, static_argnames=("k", "return_passes", "interpret"))
 def topk_l2(q: jax.Array, x: jax.Array, k: int, *, valid: jax.Array | None = None,
-            interpret: bool | None = None):
+            return_passes: bool = False, interpret: bool | None = None):
     """Fused blocked distance + top-k: the (Q, N) matrix never hits HBM.
 
     Args:
@@ -92,14 +92,18 @@ def topk_l2(q: jax.Array, x: jax.Array, k: int, *, valid: jax.Array | None = Non
         DESIGN.md §10).  Masked rows never surface; queries with fewer
         than k live rows underflow as dist = +inf, id = -1.  With
         valid=None the output is bitwise the pre-mutation scan.
+      return_passes: also return the kernel's selection passes, summed
+        over the catalog's tiles, per block of 128 (padded) queries.
       interpret: see `pairwise_l2`.
 
     Returns:
       (dists (Q, k), ids (Q, k)): the k smallest squared distances per
-      query, ascending, with int32 catalog row ids.  Each (BQ, BN) tile
-      folds its distances into a running top-k held in VMEM by iterative
-      masked-min extraction (DESIGN.md §3): the catalog is read once and
-      only Q·k results are written.
+      query, ascending, with int32 catalog row ids; with `return_passes`,
+      then int32 (ceil(Q / 128),) passes.  Each (BQ, BN) tile folds its
+      distances into a running top-k held in VMEM by ranked inserts, one
+      pass per tile row that can still enter some query's top-k
+      (DESIGN.md §3): the catalog is read once and only Q·k results are
+      written.
     """
     interp = (not _on_tpu()) if interpret is None else interpret
     qq = q.shape[0]
@@ -110,12 +114,12 @@ def topk_l2(q: jax.Array, x: jax.Array, k: int, *, valid: jax.Array | None = Non
         # tail block's rows past N are masked inside the kernel)
         mask = jnp.where(_pad_rows(valid, l2_topk_kernel.BN, value=False),
                          0.0, jnp.inf)[None, :]
-    dists, ids = l2_topk_kernel.l2_topk_pallas(qp, x, k, mask=mask,
-                                              interpret=interp)
+    dists, ids, *passes = l2_topk_kernel.l2_topk_pallas(
+        qp, x, k, mask=mask, count_passes=return_passes, interpret=interp)
     dists, ids = dists.T[:qq], ids.T[:qq]
     if valid is not None:
         ids = jnp.where(jnp.isfinite(dists), ids, -1)
-    return dists, ids
+    return (dists, ids, *passes)
 
 
 @partial(jax.jit, static_argnames=("k", "chunk"))

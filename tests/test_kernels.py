@@ -53,6 +53,64 @@ def test_topk_l2_tombstones(q, n, k, live):
     assert np.array(valid)[got_i[got_i >= 0]].all()
 
 
+def _staircase(rng, q, n, bn=128):
+    """Rows by decreasing distance to every query: tile t's rows hold
+    2 (T - 1 - t) threes then twos, and one last entry of 2 or 3 (ties
+    inside the tile); queries are 0/1.  Dropping two threes takes at
+    least 6 off a distance, the last entry moves it by at most 5."""
+    t = -(-n // bn)
+    tile = np.arange(n) // bn
+    x = np.full((n, 2 * (t - 1) + 1), 2)
+    x[:, :-1] += np.arange(2 * (t - 1))[None, :] < 2 * (t - 1 - tile)[:, None]
+    x[:, -1] += rng.integers(0, 2, n)
+    return rng.integers(0, 2, (q, x.shape[1])), x
+
+
+def _first_tile_best(rng, q, n, bn=128):
+    """The first tile, and every other later row, are copies of one 0/1
+    row; the other later rows are 2/3; queries are 0/1.  No later row is
+    strictly nearer than the first tile's k-th best: the copies tie it."""
+    odd_later = ((np.arange(n) >= bn) & (np.arange(n) % 2 == 1))[:, None]
+    x = np.where(odd_later, rng.integers(2, 4, (n, 8)),
+                 rng.integers(0, 2, 8))
+    return rng.integers(0, 2, (q, 8)), x
+
+
+def _ints(rng, q, n):
+    return rng.integers(0, 4, (q, 4)), rng.integers(0, 4, (n, 4))
+
+
+@pytest.mark.parametrize("case,data,q,n,k,live,passes", [
+    ("k1", _ints, 5, 1000, 1, None, None),
+    ("k64", _ints, 5, 1000, 64, None, None),
+    ("k200_above_bn", _ints, 5, 1000, 200, None, None),
+    ("ragged_two_blocks", _ints, 130, 300, 64, None, None),
+    ("tombstones", _ints, 5, 1000, 64, 0.5, None),
+    ("fewer_live_than_k", _ints, 5, 1000, 64, 0.03, None),
+    ("every_tile_inserts", _staircase, 130, 1000, 64, None, [8 * 64] * 2),
+    ("first_tile_only", _first_tile_best, 5, 1000, 64, None, [64]),
+])
+def test_topk_l2_exact(case, data, q, n, k, live, passes):
+    """The gated running top-k (interpret mode) against the oracle, bit
+    for bit, on integer data where every distance is exact in float32 and
+    duplicate rows tie: ties go to the lower id, tombstoned and ragged-tail
+    rows never surface, underflow slots are (+inf, -1).  Where the catalog's
+    order fixes it, the kernel's pass count too: k passes for a tile that
+    must take k rows, none for one that can take none."""
+    rng = np.random.default_rng(15)
+    qa, xa = (jnp.array(a.astype(np.float32)) for a in data(rng, q, n))
+    valid = None if live is None else jnp.array(rng.random(n) < live)
+    gd, gi, got_passes = ops.topk_l2(qa, xa, k, valid=valid,
+                                     return_passes=True)
+    wd, wi = ref.l2_topk_ref(qa, xa, k, valid)
+    np.testing.assert_array_equal(np.array(gd), np.array(wd))
+    np.testing.assert_array_equal(np.array(gi), np.array(wi))
+    if valid is not None and live < 0.1:
+        assert (np.array(gi) == -1).any(), case
+    if passes is not None:
+        np.testing.assert_array_equal(np.array(got_passes), passes)
+
+
 @pytest.mark.parametrize("q,n,m,c", [(2, 64, 4, 16), (128, 300, 8, 256),
                                      (5, 1000, 16, 256), (1, 50, 2, 4)])
 def test_pq_adc(q, n, m, c):

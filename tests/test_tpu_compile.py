@@ -62,15 +62,22 @@ def _compile(fn, *args):
 
 
 @pytest.mark.parametrize("masked", [False, True])
-@pytest.mark.parametrize("k", [16, 64])
-def test_topk_l2_compiles(shape, k, masked):
+@pytest.mark.parametrize("k,d,passes", [
+    pytest.param(16, D, False, id="16"),
+    pytest.param(64, D, False, id="64"),
+    # the GIST1M cell's width, with the selection pass counter
+    pytest.param(64, 960, True, id="64-d960-passes"),
+])
+def test_topk_l2_compiles(shape, k, d, passes, masked):
     if masked:
         _compile(lambda q, x, v: ops.topk_l2(q, x, k, valid=v,
+                                             return_passes=passes,
                                              interpret=False),
-                 shape((B, D)), shape((N, D)), shape((N,), jnp.bool_))
+                 shape((B, d)), shape((N, d)), shape((N,), jnp.bool_))
     else:
-        _compile(lambda q, x: ops.topk_l2(q, x, k, interpret=False),
-                 shape((B, D)), shape((N, D)))
+        _compile(lambda q, x: ops.topk_l2(q, x, k, return_passes=passes,
+                                          interpret=False),
+                 shape((B, d)), shape((N, d)))
 
 
 @pytest.mark.parametrize("k", [10, 64, 200])
