@@ -16,8 +16,10 @@ One chip (the default), three arms on the same catalog and trace:
          kernel over probed lists fetched from HBM.
 Checks: the flat and IVF steps call a Pallas kernel (`tpu_custom_call` in
 the compiled step); the flat top-10 equals a float64 NumPy scan on the
-host and its NAG equals the exact arm's; the IVF kernel equals its XLA
-oracle on the same inputs.  The IVF build trains k-means in 64Ki-row
+host and its NAG equals the exact arm's; every catalog row sits in the
+IVF list of its nearest centroid, up to a tie (the arm also prints the
+index's `list_stats()`); the IVF kernel equals its XLA oracle on the same
+inputs.  The IVF build trains k-means in 64Ki-row
 chunks (`repro.index.kmeans.CHUNK`), 256 MiB of distances at a time.
 
 `--chips 4` runs only the sharded path, on a (1, 4) mesh of the first four
@@ -56,6 +58,8 @@ SHARDS = 4
 SCAN_CHUNK = 8192
 SAMPLE = 256          # requests checked against the host float64 scan
 TIE_RTOL = 1e-5       # distances closer than this are ties
+TIE_ULPS = 32         # list check: a centroid this close to the nearest ties
+EPS32 = float(np.finfo(np.float32).eps)
 NAG_ATOL = 1e-5       # flat vs exact arm
 
 
@@ -102,6 +106,33 @@ def same_up_to_ties(catalog, q, ids, ref_ids) -> tuple[bool, int]:
     d_got = np.sort(dist64(catalog, q, ids), 1)
     d_ref = np.sort(dist64(catalog, q, ref_ids), 1)
     return bool(np.allclose(d_got, d_ref, rtol=TIE_RTOL, atol=0.0)), differ
+
+
+def lists_not_nearest(catalog_dev, idx, rows: int = 65536) -> int:
+    """Catalog rows in no inverted list, or in a list whose centroid is
+    farther than their nearest one beyond a tie (TIE_ULPS float32 ulps of
+    the squared norms): 0 when the lists are the centroids' Voronoi
+    cells."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+
+    table = np.asarray(idx.invlists)
+    own = np.full(N, -1, np.int32)
+    own[table[table >= 0]] = np.nonzero(table >= 0)[0]
+    cents = idx.centroids
+    cmax = jnp.max(jnp.sum(cents * cents, axis=1))
+
+    @jax.jit
+    def bad(x, o):
+        d = ops.pairwise_l2_xla(x, cents)
+        mine = jnp.take_along_axis(d, jnp.maximum(o, 0)[:, None], 1)[:, 0]
+        tol = TIE_ULPS * EPS32 * (jnp.sum(x * x, axis=1) + cmax)
+        return jnp.sum((o < 0) | (mine - jnp.min(d, axis=1) > tol))
+
+    return sum(int(bad(catalog_dev[s:s + rows], own[s:s + rows]))
+               for s in range(0, N, rows))
 
 
 def make_data(seed: int = 0):
@@ -204,6 +235,10 @@ def one_chip(catalog: np.ndarray, reqs: np.ndarray) -> None:
     idx = ivf.index
     log(f"  ivf: {idx.invlists.shape[0]} lists x {idx.invlists.shape[1]} "
         f"slots (P = {NPROBE * idx.invlists.shape[1]} candidates/query)")
+    log(f"  ivf list_stats: {json.dumps(idx.list_stats())}")
+    bad = lists_not_nearest(catalog_dev, idx)
+    check(bad == 0, f"ivf lists are the centroids' Voronoi cells ({bad} of "
+                    f"{N} rows in no list or not in their nearest one's)")
     qd = jnp.asarray(q[:BATCH])
     _, probe = jax.lax.top_k(-ops.pairwise_l2_xla(qd, idx.centroids), NPROBE)
     cand = idx.invlists[probe].reshape(BATCH, -1)

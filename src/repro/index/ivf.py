@@ -34,21 +34,23 @@ import numpy as np
 from repro.index.base import (MutableRows, _flat_set, arrays_bytes,
                               check_finite_queries, pad_ids, pad_rows,
                               run_device, track_jit)
-from repro.index.kmeans import kmeans
+from repro.index.kmeans import nearest, nearest_centroid, train
 from repro.kernels import ops
 
 
 def build_invlists(assign: np.ndarray, nlist: int, cap: int | None = None):
-    """Dense padded inverted lists from an assignment vector."""
+    """Dense padded inverted lists from an assignment vector: list `a`
+    holds the ids assigned to it in ascending order, and a list longer
+    than `cap` keeps its `cap` lowest ids."""
+    assign = np.asarray(assign)
     counts = np.bincount(assign, minlength=nlist)
     cap = int(counts.max()) if cap is None else cap
+    order = np.argsort(assign, kind="stable")    # by list, then by id
+    col = np.arange(order.shape[0]) - np.repeat(np.cumsum(counts) - counts,
+                                                 counts)
+    keep = col < cap
     table = np.full((nlist, cap), -1, np.int32)
-    cursor = np.zeros(nlist, np.int32)
-    for i, a in enumerate(assign):
-        c = cursor[a]
-        if c < cap:
-            table[a, c] = i
-            cursor[a] = c + 1
+    table[assign[order[keep]], col[keep]] = order[keep]
     return table
 
 
@@ -91,9 +93,19 @@ def invlist_device_append(invlists: jax.Array, cursor: np.ndarray,
 @track_jit("ivf_assign")
 @jax.jit
 def _assign_lists(vecs: jax.Array, centroids: jax.Array) -> jax.Array:
-    """Nearest existing centroid per (padded) incoming row."""
-    return jnp.argmin(ops.pairwise_l2_xla(vecs, centroids),
-                      axis=1).astype(jnp.int32)
+    """Nearest existing centroid per (padded) incoming row, by the rule
+    the build assigns with (`kmeans.nearest_centroid`)."""
+    return nearest_centroid(vecs, centroids)
+
+
+def ivf_probe(q, centroids, invlists, nprobe: int):
+    """(B, d) -> (B, nprobe * cap) candidate ids, -1 in padded slots: the
+    table rows of the `nprobe` lists whose centroids are nearest to each
+    request, under the `acai.ivf_probe` scope."""
+    with jax.named_scope("acai.ivf_probe"):
+        dc = ops.pairwise_l2_xla(q, centroids)          # (B, nlist)
+        _, probe = jax.lax.top_k(-dc, nprobe)            # (B, nprobe)
+        return invlists[probe].reshape(q.shape[0], -1)
 
 
 @track_jit("ivf_query")
@@ -108,9 +120,7 @@ def _ivf_query(q, emb, centroids, invlists, valid, k: int, nprobe: int,
     threads the tombstone mask through the scan (fresh builds skip it and
     stay bitwise identical to the static-catalog path)."""
     q = jnp.atleast_2d(q)
-    dc = ops.pairwise_l2_xla(q, centroids)              # (B, nlist)
-    _, probe = jax.lax.top_k(-dc, nprobe)                # (B, nprobe)
-    cand = invlists[probe].reshape(q.shape[0], -1)       # (B, nprobe*cap)
+    cand = ivf_probe(q, centroids, invlists, nprobe)
     return ops.ivf_scan_auto(q, emb, cand, k, valid if masked else None)
 
 
@@ -147,8 +157,13 @@ class IVFFlatIndex(MutableRows):
                     else self.embeddings[jnp.asarray(live)])
         nlist = min(self.nlist, max(n_live, 1))
         key = jax.random.PRNGKey(self.seed)
-        centroids, assign = kmeans(key, emb_live, nlist, self.train_iters)
-        table = build_invlists(np.asarray(assign), nlist)
+        with jax.profiler.TraceAnnotation("ivf.kmeans"):
+            centroids = jax.block_until_ready(
+                train(key, emb_live, nlist, self.train_iters))
+        with jax.profiler.TraceAnnotation("ivf.assign"):
+            assign = np.asarray(nearest(emb_live, centroids))
+        with jax.profiler.TraceAnnotation("ivf.lists"):
+            table = build_invlists(assign, nlist)
         if n_live != self.capacity:  # remap local ids -> slab row ids
             table = np.where(table >= 0, live[np.clip(table, 0, None)], -1)
         cursor = (table >= 0).sum(axis=1).astype(np.int32)
@@ -183,6 +198,20 @@ class IVFFlatIndex(MutableRows):
     def memory_bytes(self) -> int:
         return arrays_bytes(self.embeddings, self.centroids, self.invlists,
                             self.valid)
+
+    def list_stats(self) -> dict:
+        """The inverted-list table's shape, from host bookkeeping (no
+        device read): `nlist`, the table width `cap`, the mean and largest
+        list, the empty lists, and `padded_share`, the share of the table's
+        slots that hold no id (1 - rows / (nlist * cap)); `ivf_scan` walks
+        a padded slot but issues no DMA for it."""
+        sizes = self._cursor
+        nlist, cap = self.invlists.shape
+        return {"nlist": int(nlist), "cap": int(cap),
+                "mean_list": float(sizes.mean()),
+                "max_list": int(sizes.max()),
+                "empty_lists": int((sizes == 0).sum()),
+                "padded_share": 1.0 - float(sizes.sum()) / (nlist * cap)}
 
     def query(self, q: jax.Array, k: int):
         check_finite_queries(q, "IVFFlatIndex.query")

@@ -167,12 +167,12 @@ class NSWIndex(MutableRows):
         # entry points = catalog points nearest to k-means centroids: the
         # static-shape stand-in for HNSW's upper navigation layers — ensures
         # every density mode seeds the beam (DESIGN.md §3).
-        from repro.index.kmeans import kmeans as _kmeans
+        from repro.index.kmeans import train
 
         nentry = min(self.beam, len(live))
         emb_live = (self.embeddings if len(live) == self.capacity
                     else self.embeddings[jnp.asarray(live)])
-        cents, _ = _kmeans(jax.random.PRNGKey(self.seed), emb_live, nentry)
+        cents = train(jax.random.PRNGKey(self.seed), emb_live, nentry)
         d2 = ops.pairwise_l2_xla(cents, emb_live)
         near = np.asarray(jnp.argmin(d2, axis=1))
         return (jnp.asarray(graph), jnp.asarray(live[near], jnp.int32))

@@ -29,8 +29,8 @@ from repro.index.base import (MutableRows, _rows_write, arrays_bytes,
                               check_finite_queries, pad_rows, run_device,
                               track_jit)
 from repro.index.ivf import (_assign_lists, build_invlists,
-                             invlist_device_append)
-from repro.index.kmeans import kmeans
+                             invlist_device_append, ivf_probe)
+from repro.index.kmeans import kmeans, train
 from repro.kernels import ops
 
 
@@ -82,7 +82,7 @@ class PQCodec:
         sub = jnp.asarray(data, jnp.float32).reshape(n, m, self.dsub)
         keys = jax.random.split(jax.random.PRNGKey(seed), m)
         ksub = min(self.ksub, n)
-        cents, _ = jax.vmap(lambda k, x: kmeans(k, x, ksub, train_iters))(
+        cents = jax.vmap(lambda k, x: train(k, x, ksub, train_iters))(
             keys, sub.transpose(1, 0, 2)
         )
         if ksub < self.ksub:  # pad tiny training sets
@@ -113,9 +113,7 @@ def _ivfpq_query(q, emb, centroids, invlists, codes, codebooks, valid,
                  k: int, nprobe: int, refine: int, masked: bool):
     q = jnp.atleast_2d(q)
     b = q.shape[0]
-    dc = ops.pairwise_l2_xla(q, centroids)
-    _, probe = jax.lax.top_k(-dc, nprobe)
-    cand = invlists[probe].reshape(b, -1)               # (B, P)
+    cand = ivf_probe(q, centroids, invlists, nprobe)    # (B, P)
     if masked:  # tombstoned rows -> the -1 invalid-slot convention
         cand = jnp.where(
             (cand >= 0) & valid[jnp.clip(cand, 0, emb.shape[0] - 1)],

@@ -9,6 +9,7 @@ shapes only, nothing runs.  The topology is described inside a
 module-scoped fixture, so collecting this file touches no TPU library.
 """
 
+import importlib
 import os
 
 import jax
@@ -22,6 +23,8 @@ from repro.index import exact as exact_index
 from repro.index import ivf as ivf_index
 from repro.index.candidates import index_candidate_fn_batched
 from repro.kernels import ops
+
+kmeans = importlib.import_module("repro.index.kmeans")
 
 N, D, B = 1_000_000, 128, 64
 NLIST, NPROBE, LIST_CAP = 1024, 16, 2048
@@ -89,6 +92,24 @@ def test_ivf_scan_compiles(shape, k):
                  shape((B, NPROBE * 256), jnp.int32))
     # the catalog is read in place: no (N, d) copy on the device
     assert c.memory_analysis().temp_size_in_bytes < N * D * 4
+
+
+@pytest.mark.parametrize("step", ["train", "nearest"])
+def test_kmeans_compares_float32(shape, step):
+    """The IVF build at 1M rows and 1024 centroids, its Lloyd iterations
+    and its final assignment: no reduce of the compiled program carries a
+    bfloat16 value (the TPU compiler keeps `argmin`'s running minimum in
+    bfloat16 where only its index is used), and no (N, nlist) distance
+    array is held at once."""
+    if step == "train":
+        c = kmeans.train.lower(shape((2,), jnp.uint32), shape((N, D)),
+                               k=NLIST).compile()
+    else:
+        c = kmeans.nearest.lower(shape((N, D)), shape((NLIST, D))).compile()
+    narrowed = [line for line in c.as_text().splitlines()
+                if " reduce(" in line and "bf16[" in line]
+    assert not narrowed, narrowed[:1]
+    assert c.memory_analysis().temp_size_in_bytes < N * NLIST * 4 // 4
 
 
 def test_pq_adc_compiles(shape):
